@@ -38,6 +38,7 @@ from benchmarks import common
 from repro.core import masks
 from repro.kernels import tuning
 from repro.kernels.select import fused_select, select_ref
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import attention_core
 
 SELECT_VOCABS = (32_768, 131_072)
@@ -162,6 +163,7 @@ def main(argv=None):
                     help="restrict --tune to these ops "
                          f"(default: all of {sorted(tuning.OP_DEFAULTS)})")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.tune:
         ops = tuple(args.tune_ops.split(",")) if args.tune_ops else None
         tuning.run_sweep(ops, vocabs=SELECT_VOCABS,

@@ -33,6 +33,7 @@ import numpy as np
 
 from benchmarks import common
 from repro.configs.base import ServeConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _run_static_trace(eng, reqs, max_batch):
@@ -273,6 +274,7 @@ def main(argv=None):
                          "SamplingParams (temperature 0.7, own seed) — "
                          "exercises mixed greedy/sampled batches")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.smoke:
         import jax

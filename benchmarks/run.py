@@ -61,6 +61,8 @@ def run_all() -> None:
 
 def main(argv=None) -> None:
     argv = sys.argv[1:] if argv is None else argv
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if not argv or argv[0] in ("all",):
         run_all()
         return

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs.base import ServeConfig
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_model
 from repro.serving import Request, make_engine
 from repro.serving.server import serve_http
@@ -42,6 +43,7 @@ def _post(base, body):
 
 
 def main():
+    enable_compile_cache()
     params = init_model(jax.random.PRNGKey(0), CFG)
     rng = np.random.default_rng(0)
     prompt = rng.integers(2, CFG.vocab_size, P, dtype=np.int32)

@@ -13,16 +13,13 @@
 Each subpackage: ``<name>.py`` (pl.pallas_call + BlockSpec), ``ops.py``
 (jit'd model-layout wrapper), ``ref.py`` (pure-jnp oracle). Validated with
 ``interpret=True`` shape/dtype sweeps in tests/test_kernels.py /
-tests/test_select_kernel.py; every op resolves ``interpret=None`` through
-:func:`default_interpret`, so real accelerators compile the kernels and
-CPU runs emulate them without call sites having to care.
+tests/test_select_kernel.py, and compiled for a described TPU v5e at
+qwen2-0.5b widths in tests/test_tpu_compile.py; every op resolves
+``interpret=None`` through :func:`default_interpret`, so real accelerators
+compile the kernels and CPU runs emulate them without call sites having to
+care.
 """
 import jax
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams (~0.5); support both.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    getattr(_pltpu, "TPUCompilerParams")
 
 
 def default_interpret() -> bool:
@@ -40,6 +37,24 @@ def default_interpret() -> bool:
 def resolve_interpret(interpret) -> bool:
     """``None`` -> :func:`default_interpret`; explicit bools pass through."""
     return default_interpret() if interpret is None else bool(interpret)
+
+
+def pallas_calls(jaxpr):
+    """``(kernel name, interpreted?)`` for every ``pallas_call`` in a traced
+    program (``jax.make_jaxpr`` output), sub-jaxprs included: which kernels
+    the program runs, and whether any of them runs under the interpreter."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    calls = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls.append((eqn.params["jaxpr"].debug_info.func_name,
+                          bool(eqn.params["interpret"])))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    calls.extend(pallas_calls(sub))
+    return calls
 
 
 from repro.kernels import block_attn, decode_attn, select, xent  # noqa: F401,E402

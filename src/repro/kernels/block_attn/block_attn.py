@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams, resolve_interpret
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -131,7 +131,7 @@ def block_attention(q, k, v, *, mode: str = "block_causal",
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams, resolve_interpret
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -132,7 +132,7 @@ def decode_attention_partial(q, k_cache, v_cache, cache_len, *,
             pltpu.VMEM((BqG, 1), jnp.float32),
             pltpu.VMEM((BqG, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(lens, q, k_cache, v_cache)
@@ -252,7 +252,7 @@ def paged_decode_attention_partial(q, k_pages, v_pages, page_table,
             jax.ShapeDtypeStruct((b, Kv, BqG, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, Kv, BqG, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(pt, lens, q, k_pages, v_pages)

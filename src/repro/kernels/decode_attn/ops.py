@@ -72,7 +72,8 @@ def decode_attention(q, k_cache, v_cache, k_blk, v_blk, cache_len, *,
     """Model-layout decode attention.
 
     q: (b, Bq, Kv, G, hd); k/v_cache: (b, S, Kv, hd); k/v_blk: (b, Bq, Kv, hd);
-    cache_len: scalar int32 — valid cache prefix. Returns (b, Bq, Kv, G, hd).
+    cache_len: scalar int32 — valid cache prefix. Returns (b, Bq, Kv, G, hd)
+    in q's dtype (fp32 accumulation inside), like the jnp attention path.
     """
     b, Bq, Kv, G, hd = q.shape
     S = k_cache.shape[1]
@@ -99,7 +100,8 @@ def decode_attention(q, k_cache, v_cache, k_blk, v_blk, cache_len, *,
     blk_part = _block_partial(qf, kbf, vbf, scale=scale, softcap=softcap,
                               window=window, g=G)
     out = softmax_combine([cache_part, blk_part])
-    return out.reshape(b, Kv, Bq, G, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(b, Kv, Bq, G, hd).transpose(0, 2, 1, 3, 4).astype(
+        q.dtype)
 
 
 @functools.partial(
@@ -116,7 +118,8 @@ def paged_decode_attention(q, k_pages, v_pages, k_blk, v_blk, page_table,
     q: (b, Bq, Kv, G, hd); k/v_pages: (n_pages, page, Kv, hd) pools shared
     across lanes; k/v_blk: (b, Bq, Kv, hd) fresh in-block KV;
     page_table: (b, n_tables) int32 (-1 = unallocated); cache_lens: scalar
-    or (b,) int32 — per-lane valid cache prefix. Returns (b, Bq, Kv, G, hd).
+    or (b,) int32 — per-lane valid cache prefix. Returns (b, Bq, Kv, G, hd)
+    in q's dtype.
     """
     b, Bq, Kv, G, hd = q.shape
     cfg = tuning.resolve(
@@ -140,4 +143,5 @@ def paged_decode_attention(q, k_pages, v_pages, k_blk, v_blk, page_table,
                               scale=scale, softcap=softcap, window=window,
                               g=G)
     out = softmax_combine([cache_part, blk_part])
-    return out.reshape(b, Kv, Bq, G, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(b, Kv, Bq, G, hd).transpose(0, 2, 1, 3, 4).astype(
+        q.dtype)

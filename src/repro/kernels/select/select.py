@@ -39,7 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams, resolve_interpret
+from repro.kernels import resolve_interpret
+
 
 def _select_kernel(h_ref, w_ref, mask_ref, cand_ref, conf_ref,
                    m_scr, l_scr, i_scr, *, block_t, block_v, n_v, v_total,
@@ -80,9 +81,8 @@ def _select_kernel(h_ref, w_ref, mask_ref, cand_ref, conf_ref,
     def _finalize():
         # the argmax logit is the running max, so softmax(conf) = 1/l
         conf = 1.0 / l_scr[...]
-        live = mask_ref[...].reshape(block_t, 1) != 0
-        conf_ref[...] = jnp.where(live, conf, -jnp.inf).reshape(conf_ref.shape)
-        cand_ref[...] = i_scr[...].reshape(cand_ref.shape)
+        conf_ref[...] = jnp.where(mask_ref[...] != 0, conf, -jnp.inf)
+        cand_ref[...] = i_scr[...]
 
 
 def select_forward(hidden, w, masked, *, v_total: Optional[int] = None,
@@ -93,7 +93,10 @@ def select_forward(hidden, w, masked, *, v_total: Optional[int] = None,
 
     T must be a multiple of block_t and Vp of block_v (ops.py pads);
     ``v_total`` is the true vocab size — columns at/after it are padding
-    and masked to -inf in-kernel."""
+    and masked to -inf in-kernel. The per-row operands travel as
+    ``(T, 1)`` columns in ``(block_t, 1)`` blocks: Mosaic tiles a 1-D
+    ``(block_t,)`` block at 128 while XLA lays a longer 1-D array out in
+    one tile, so 1-D blocks fail to compile for the TPU at any T > 128."""
     T, d = hidden.shape
     Vp = w.shape[1]
     v_total = Vp if v_total is None else v_total
@@ -104,28 +107,29 @@ def select_forward(hidden, w, masked, *, v_total: Optional[int] = None,
     kernel = functools.partial(_select_kernel, block_t=block_t,
                                block_v=block_v, n_v=n_v, v_total=v_total,
                                softcap=softcap)
-    return pl.pallas_call(
+    cand, conf = pl.pallas_call(
         kernel,
         grid=(n_t, n_v),
         in_specs=[
             pl.BlockSpec((block_t, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, block_v), lambda i, j: (0, j)),
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
+            pl.BlockSpec((block_t, 1), lambda i, j: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
+            pl.BlockSpec((block_t, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((block_t, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1), jnp.int32),
+            jax.ShapeDtypeStruct((T, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(hidden, w, masked)
+    )(hidden, w, masked.reshape(T, 1))
+    return cand[:, 0], conf[:, 0]

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams, resolve_interpret
+from repro.kernels import resolve_interpret
 
 
 def _xent_kernel(h_ref, w_ref, y_ref, loss_ref, m_scr, l_scr, t_scr, *,
@@ -39,7 +39,7 @@ def _xent_kernel(h_ref, w_ref, y_ref, loss_ref, m_scr, l_scr, t_scr, *,
 
     vpos = vi * block_v + jax.lax.broadcasted_iota(
         jnp.int32, (block_t, block_v), 1)
-    y = y_ref[...].reshape(block_t, 1)                    # (block_t, 1)
+    y = y_ref[...]                                        # (block_t, 1)
     t_scr[...] = t_scr[...] + jnp.sum(
         jnp.where(vpos == y, logits, 0.0), axis=-1, keepdims=True)
 
@@ -53,14 +53,17 @@ def _xent_kernel(h_ref, w_ref, y_ref, loss_ref, m_scr, l_scr, t_scr, *,
     @pl.when(vi == n_v - 1)
     def _finalize():
         logz = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
-        loss_ref[...] = (logz - t_scr[...]).reshape(loss_ref.shape)
+        loss_ref[...] = logz - t_scr[...]
 
 
 def xent_forward(hidden, w, targets, *, block_t: int = 128,
                  block_v: int = 512, interpret=None):
     """hidden: (T, d); w: (d, V); targets: (T,) int32 -> loss (T,) fp32.
 
-    T must be a multiple of block_t, V of block_v (ops.py pads)."""
+    T must be a multiple of block_t, V of block_v (ops.py pads). Targets
+    and losses travel as ``(T, 1)`` columns in ``(block_t, 1)`` blocks,
+    which Mosaic tiles the way XLA lays them out (1-D blocks do not
+    compile for the TPU at T > 128)."""
     T, d = hidden.shape
     V = w.shape[1]
     assert T % block_t == 0 and V % block_v == 0
@@ -68,22 +71,23 @@ def xent_forward(hidden, w, targets, *, block_t: int = 128,
 
     kernel = functools.partial(_xent_kernel, block_t=block_t,
                                block_v=block_v, n_v=n_v)
-    return pl.pallas_call(
+    loss = pl.pallas_call(
         kernel,
         grid=(n_t, n_v),
         in_specs=[
             pl.BlockSpec((block_t, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, block_v), lambda i, j: (0, j)),
-            pl.BlockSpec((block_t,), lambda i, j: (i,)),
+            pl.BlockSpec((block_t, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_t,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((T,), jnp.float32),
+        out_specs=pl.BlockSpec((block_t, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((T, 1), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
             pltpu.VMEM((block_t, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
-    )(hidden, w, targets)
+    )(hidden, w, targets.reshape(T, 1))
+    return loss[:, 0]

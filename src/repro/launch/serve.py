@@ -68,8 +68,10 @@ def main():
                                     "..", "..", ".."))
     from benchmarks import common
     from repro.configs.base import ServeConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import Request, efficiency_report, make_engine
 
+    enable_compile_cache()
     if args.ckpt:
         import jax
         from repro.checkpoint import restore

@@ -34,8 +34,10 @@ def main():
     from repro.configs.registry import get_config
     from repro.core import masks
     from repro.data import Corpus, TaskSpec
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.training import trainer
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced(dtype="float32")

@@ -136,3 +136,50 @@ def test_arrival_trace_ordering(params, requests):
     resp = eng.generate(staggered)
     assert sorted(r.id for r in resp) == [0, 1, 2, 3, 4]
     assert all(r.latency_s >= 0 for r in resp)
+
+
+def test_bf16_paged_kernel_fused_select_engine(monkeypatch):
+    """CPU rehearsal of the chip's serving path: the continuous engine in
+    bfloat16 (the dtype of every published config) over the paged layout,
+    with the paged decode kernel and the Pallas fused select, both in
+    interpret mode. Every request completes with in-vocab ids, and the
+    decode step really contains both kernels."""
+    import functools
+
+    from repro.core import diffusion
+    from repro.kernels import pallas_calls
+    from repro.models import init_model
+
+    # off the TPU, fused select resolves "auto" to the streaming scan;
+    # steer it to the Pallas kernel (interpreted here) for this test only
+    monkeypatch.setattr(diffusion, "confidence_and_candidates_fused",
+                        functools.partial(
+                            diffusion.confidence_and_candidates_fused,
+                            impl="pallas"))
+    cfg = get_config("qwen2-0.5b").reduced(dtype="bfloat16")
+    params = init_model(jax.random.PRNGKey(1), cfg)
+    serve = ServeConfig(max_batch=2, block_size=B, gen_length=G,
+                        sampler="cdlm", conf_threshold=0.5,
+                        scheduler="continuous", cache_layout="paged",
+                        fused_select=True)
+    eng = make_engine(params, cfg, serve, prompt_len=P,
+                      use_paged_kernel=True)
+    eng.warmup(per_request=True)
+    rng = np.random.default_rng(1)
+    reqs = [Request(prompt=rng.integers(2, cfg.mask_token_id, P,
+                                        dtype=np.int32), id=i)
+            for i in range(3)]
+    resp = eng.generate(reqs)
+    assert sorted(r.id for r in resp) == [0, 1, 2]
+    for r in resp:
+        assert r.finish_reason in ("stop", "length")
+        assert 1 <= r.gen_length <= G
+        ids = np.asarray(r.tokens)[:r.gen_length]
+        assert ((ids >= 0) & (ids < cfg.vocab_size)).all()
+
+    run = np.ones((serve.max_batch,), bool)
+    step = jax.make_jaxpr(
+        lambda p, s, r: eng._decode_block(p, s, r, sampled=False))(
+            params, eng._state, run)
+    names = {name for name, _ in pallas_calls(step)}
+    assert {"_select_kernel", "_paged_decode_kernel"} <= names, names
