@@ -1,0 +1,1 @@
+"""The chip benchmark: see PERF.md and BENCHMARK.json."""
