@@ -107,6 +107,7 @@ from repro.core.block_loop import (
 )
 from repro.core.sampler import SAMPLERS
 from repro.models import forward, unembed_matrix
+from repro.serving import tracing
 from repro.serving.api import (
     BlockEvent,
     GenerationOutput,
@@ -560,6 +561,12 @@ class ContinuousEngine(_RequestStepper):
                                              eos_id=eos))
         self._warm = False
         self._next_id = 0
+        # work counters and host seconds per phase over the engine's life
+        # (``counters()``): fixed keys, written by the stepping thread only
+        self.clock = tracing.PhaseClock()
+        self._counts = dict.fromkeys(
+            ("steps", "admit_calls", "admitted_lanes", "forwards",
+             "lane_iters_active", "blocks_emitted"), 0)
         self._reset()
 
     # -- jitted state transitions -------------------------------------------
@@ -605,11 +612,13 @@ class ContinuousEngine(_RequestStepper):
         if self.paged:
             cache, ok = C.alloc(cache, admit, 0,
                                 spec.prompt_len + spec.block_size)
-        out = forward(params, tokens[:, :spec.prompt_len], cfg=cfg,
-                      mode=masks.BLOCK_CAUSAL, prompt_len=spec.full_prompt_len,
-                      block_size=spec.block_size, attn_impl=spec.attn_impl,
-                      return_logits=False)
-        cache = C.commit_rows(cache, out.emissions, 0, admit)
+        with jax.named_scope(tracing.PREFILL):
+            out = forward(params, tokens[:, :spec.prompt_len], cfg=cfg,
+                          mode=masks.BLOCK_CAUSAL,
+                          prompt_len=spec.full_prompt_len,
+                          block_size=spec.block_size,
+                          attn_impl=spec.attn_impl, return_logits=False)
+            cache = C.commit_rows(cache, out.emissions, 0, admit)
         return state._replace(
             tokens=tokens, cache=cache,
             blk=jnp.where(admit, 0, state.blk),
@@ -673,6 +682,7 @@ class ContinuousEngine(_RequestStepper):
             act = jnp.any(bt == cfg.mask_token_id, axis=-1) & live
             return jnp.any(act) & (it < B)
 
+        @jax.named_scope(tracing.REFINE)
         def body(st):
             tokens, steps, calls, keys, it = st
             bt = slice_blocks(tokens)
@@ -684,18 +694,20 @@ class ContinuousEngine(_RequestStepper):
                 use_long_window=self._use_long_window,
                 paged_attention_fn=self._paged_attention_fn,
                 return_hidden=fused)
-            if fused:
-                cand, conf = D.confidence_and_candidates_fused(
-                    net, unembed_matrix(params, cfg), bt, cfg.mask_token_id,
-                    0.0, None, softcap=cfg.final_logit_softcap)
-            elif sampled:
-                cand, conf = D.confidence_and_candidates_per_lane(
-                    net, bt, cfg.mask_token_id, state.temps, subs)
-            else:
-                cand, conf = D.confidence_and_candidates(
-                    net, bt, cfg.mask_token_id, 0.0, None)
-            sel = D.select_threshold_in_block(conf, all_block,
-                                              state.taus[:, None])
+            with jax.named_scope(tracing.SELECT):
+                if fused:
+                    cand, conf = D.confidence_and_candidates_fused(
+                        net, unembed_matrix(params, cfg), bt,
+                        cfg.mask_token_id, 0.0, None,
+                        softcap=cfg.final_logit_softcap)
+                elif sampled:
+                    cand, conf = D.confidence_and_candidates_per_lane(
+                        net, bt, cfg.mask_token_id, state.temps, subs)
+                else:
+                    cand, conf = D.confidence_and_candidates(
+                        net, bt, cfg.mask_token_id, 0.0, None)
+                sel = D.select_threshold_in_block(conf, all_block,
+                                                  state.taus[:, None])
             sel = sel & active[:, None]
             bt = jnp.where(sel, cand.astype(bt.dtype), bt)
             return (scatter_blocks(tokens, bt),
@@ -709,11 +721,13 @@ class ContinuousEngine(_RequestStepper):
         # commit pass: recompute the finalized blocks' KV exactly, only for
         # the lanes that ran, each at its own offset (only emissions are
         # consumed, so the lm_head is always skipped here)
-        _, emissions = lane_block_forward(
-            params, tokens, starts, state.cache, cfg=cfg, spec=spec,
-            use_long_window=self._use_long_window,
-            paged_attention_fn=self._paged_attention_fn, return_hidden=True)
-        cache = C.commit_rows(state.cache, emissions, starts, live)
+        with jax.named_scope(tracing.COMMIT):
+            _, emissions = lane_block_forward(
+                params, tokens, starts, state.cache, cfg=cfg, spec=spec,
+                use_long_window=self._use_long_window,
+                paged_attention_fn=self._paged_attention_fn,
+                return_hidden=True)
+            cache = C.commit_rows(state.cache, emissions, starts, live)
         calls = calls + 1
 
         bt = slice_blocks(tokens)
@@ -739,8 +753,10 @@ class ContinuousEngine(_RequestStepper):
         # blocks must not be re-emitted to stream consumers
         self._emitted: Dict[int, int] = {}
         self._t0 = time.perf_counter()
-        self._pool_samples: List[int] = []
-        self._live_samples: List[int] = []
+        # pages in use and lanes run, sampled at every decode step: running
+        # peak, sum and count
+        self._pool = [0, 0, 0]
+        self._lanes = [0, 0, 0]
         self._preemptions = 0
         self._stall_rounds = 0
         # host mirror of the device page allocator (paged layout): per-lane
@@ -752,6 +768,10 @@ class ContinuousEngine(_RequestStepper):
         self._host_hi = np.zeros((self.n_lanes,), np.int64)
         self._host_blk = np.zeros((self.n_lanes,), np.int64)
         self._host_free = self.n_pages
+        # the device's forward count and per-lane iteration counts as last
+        # read, for the counters' increments
+        self._calls_seen = 0
+        self._steps_seen = np.zeros((self.n_lanes,), np.int64)
 
     # -- host page-accounting mirror (paged layout) --------------------------
     def _host_target_hi(self, blk: int) -> int:
@@ -890,127 +910,190 @@ class ContinuousEngine(_RequestStepper):
         allocation result is ever read back. In-flight lanes allocate
         before admissions (newcomers can't starve a running lane), and most
         boundaries find their pages already claimed by the previous step's
-        prefetch.
+        prefetch. The step's one read of device results is the ``sync``
+        phase; each phase is a span of :mod:`repro.serving.tracing`.
         """
+        with self.clock.span("step"):
+            return self._step()
+
+    def _step(self) -> List[BlockEvent]:
         N, P, B = self.n_lanes, self.spec.prompt_len, self.spec.block_size
+        span = self.clock.span
         state = self._state
         now = time.perf_counter() - self._t0
 
-        # ---- paged: back the in-flight lanes' current blocks FIRST ----
-        live = np.asarray([f is not None for f in self._flights])
-        run = np.zeros((N,), bool)
-        if self.paged and live.any():
-            run = self._host_alloc(live)
-            while not run.any():
-                # every live lane is page-starved: preempt the youngest
-                # (its pages go back to the pool, its request re-enters
-                # the queue — the request's own deterministic RNG stream
-                # makes the re-decode loss-free)
-                victims = [i for i in range(N) if live[i]]
-                victim = max(victims,
-                             key=lambda i: (self._flights[i].admit_t, i))
-                if len(victims) == 1:
-                    raise RuntimeError(
-                        "page pool exhausted with a single live lane — "
-                        "pool sizing invariant violated")
-                vrow = np.zeros((N,), bool)
-                vrow[victim] = True
-                state = self._jit_evict(state, jnp.asarray(vrow))
-                self._host_evict(vrow)
-                self._queue.insert(0, self._flights[victim].req)
-                self._flights[victim] = None
-                self._preemptions += 1
-                live[victim] = False
+        with span("pages"):
+            # ---- paged: back the in-flight lanes' current blocks FIRST ----
+            live = np.asarray([f is not None for f in self._flights])
+            run = np.zeros((N,), bool)
+            if self.paged and live.any():
                 run = self._host_alloc(live)
-            # one dispatch, no result read: the device allocator's
-            # decisions equal the host plan by construction
-            state, _ = self._jit_alloc_block(state)
-            if (live & ~run).any():
-                self._stall_rounds += 1
-        elif not self.paged:
-            run = live.copy()
+                while not run.any():
+                    # every live lane is page-starved: preempt the youngest
+                    # (its pages go back to the pool, its request re-enters
+                    # the queue — the request's own deterministic RNG
+                    # stream makes the re-decode loss-free)
+                    victims = [i for i in range(N) if live[i]]
+                    victim = max(victims,
+                                 key=lambda i: (self._flights[i].admit_t, i))
+                    if len(victims) == 1:
+                        raise RuntimeError(
+                            "page pool exhausted with a single live lane — "
+                            "pool sizing invariant violated")
+                    vrow = np.zeros((N,), bool)
+                    vrow[victim] = True
+                    state = self._jit_evict(state, jnp.asarray(vrow))
+                    self._host_evict(vrow)
+                    self._queue.insert(0, self._flights[victim].req)
+                    self._flights[victim] = None
+                    self._preemptions += 1
+                    live[victim] = False
+                    run = self._host_alloc(live)
+                # one dispatch, no result read: the device allocator's
+                # decisions equal the host plan by construction
+                state, _ = self._jit_alloc_block(state)
+                if (live & ~run).any():
+                    self._stall_rounds += 1
+            elif not self.paged:
+                run = live.copy()
 
-        # ---- admission at the block boundary ----
-        # paged: budgeted by the mirror's free *pages* for prompt + next
-        # block, not by whole-sequence reservation — a request enters as
-        # soon as its next block can be backed
-        free = [i for i in range(N) if self._flights[i] is None]
+            # ---- admission at the block boundary ----
+            # paged: budgeted by the mirror's free *pages* for prompt + next
+            # block, not by whole-sequence reservation — a request enters
+            # as soon as its next block can be backed
+            admitted: List[int] = []
+            for lane in range(N):
+                if self._flights[lane] is not None:
+                    continue
+                if not self._queue or self._queue[0].arrival_s > now:
+                    break
+                if self.paged and self._host_free < self._admit_pages:
+                    break
+                req = self._queue.pop(0)
+                self._flights[lane] = _Flight(
+                    req, self._resolved[req.id], admit_t=now,
+                    arrival=self._arrival.get(req.id, req.arrival_s))
+                admitted.append(lane)
+                if self.paged:
+                    self._host_free -= self._admit_pages
+                    self._host_hi[lane] = self._admit_pages
+                    self._host_blk[lane] = 0
+
         admit = np.zeros((N,), bool)
-        prompts = np.zeros((N, P), np.int32)
-        nblocks = np.zeros((N,), np.int32)
-        temps = np.zeros((N,), np.float32)
-        taus = np.zeros((N,), np.float32)
-        eos = np.zeros((N,), np.int32)
-        keys = np.zeros((N, 2), np.uint32)
-        for lane in free:
-            if not self._queue or self._queue[0].arrival_s > now:
-                break
-            if self.paged and self._host_free < self._admit_pages:
-                break
-            req = self._queue.pop(0)
-            rp = self._resolved[req.id]
-            self._flights[lane] = _Flight(
-                req, rp, admit_t=now,
-                arrival=self._arrival.get(req.id, req.arrival_s))
-            admit[lane] = True
-            prompts[lane] = np.asarray(req.prompt)
-            nblocks[lane] = self._lane_nblocks(rp)
-            temps[lane] = rp.temperature
-            taus[lane] = rp.conf_threshold
-            eos[lane] = rp.eos_token_id
-            keys[lane] = _lane_key(rp)
-            if self.paged:
-                self._host_free -= self._admit_pages
-                self._host_hi[lane] = self._admit_pages
-                self._host_blk[lane] = 0
-        if admit.any():
-            state, _ = self._jit_admit(
-                self.params, state, jnp.asarray(prompts), jnp.asarray(admit),
-                jnp.asarray(nblocks), jnp.asarray(temps), jnp.asarray(taus),
-                jnp.asarray(eos), jnp.asarray(keys))
-            run = run | admit
+        if admitted:
+            flights = [self._flights[lane] for lane in admitted]
+            with span("admit",
+                      ids=tracing.ids(f.req.id for f in flights)):
+                prompts = np.zeros((N, P), np.int32)
+                nblocks = np.zeros((N,), np.int32)
+                temps = np.zeros((N,), np.float32)
+                taus = np.zeros((N,), np.float32)
+                eos = np.zeros((N,), np.int32)
+                keys = np.zeros((N, 2), np.uint32)
+                for lane, fl in zip(admitted, flights):
+                    admit[lane] = True
+                    prompts[lane] = np.asarray(fl.req.prompt)
+                    nblocks[lane] = self._lane_nblocks(fl.rp)
+                    temps[lane] = fl.rp.temperature
+                    taus[lane] = fl.rp.conf_threshold
+                    eos[lane] = fl.rp.eos_token_id
+                with span("admit.keys"):
+                    for lane, fl in zip(admitted, flights):
+                        keys[lane] = _lane_key(fl.rp)
+                state, _ = self._jit_admit(
+                    self.params, state, jnp.asarray(prompts),
+                    jnp.asarray(admit), jnp.asarray(nblocks),
+                    jnp.asarray(temps), jnp.asarray(taus), jnp.asarray(eos),
+                    jnp.asarray(keys))
+                run = run | admit
         if all(f is None for f in self._flights):
             # nothing decoding and nothing arrived yet: idle to the next
             # arrival instead of spinning
             self._state = state
             if self._queue:
-                wait = self._queue[0].arrival_s - (time.perf_counter()
-                                                   - self._t0)
-                if wait > 0:
-                    time.sleep(wait)
+                with span("sleep"):
+                    wait = self._queue[0].arrival_s - (time.perf_counter()
+                                                       - self._t0)
+                    if wait > 0:
+                        time.sleep(wait)
             return []
-        if self.paged:
-            self._pool_samples.append(self.n_pages - self._host_free)
 
         # ---- one block-level decode step for the runnable lanes ----
-        self._live_samples.append(int(run.sum()))
-        self._host_blk[run] += 1
-        state = self._jit_decode_block(self.params, state, jnp.asarray(run),
-                                       sampled=self._sampled_step())
+        with span("decode"):
+            if self.paged:
+                _sample(self._pool, self.n_pages - self._host_free)
+            _sample(self._lanes, int(run.sum()))
+            self._host_blk[run] += 1
+            state = self._jit_decode_block(self.params, state,
+                                           jnp.asarray(run),
+                                           sampled=self._sampled_step())
+            if self.paged:
+                # prefetch: claim the surviving lanes' next-block pages
+                # while this boundary's results drain — dispatched before
+                # any result is read, so the next step's in-flight alloc is
+                # a no-op
+                state, _ = self._jit_alloc_block(state)
+        with span("sync"):
+            live, calls, steps_arr = jax.device_get(
+                (state.live, state.calls, state.steps))
+        self._count(admit, int(calls), steps_arr)
         if self.paged:
-            # prefetch: claim the surviving lanes' next-block pages while
-            # this boundary's results drain — dispatched before any result
-            # is read, so the next step's in-flight alloc is a no-op
-            state, _ = self._jit_alloc_block(state)
-        live = np.asarray(state.live)
-        if self.paged:
-            self._host_alloc(live)
+            with span("pages"):
+                self._host_alloc(live)
         t_done = time.perf_counter() - self._t0
 
         # ---- block events + eviction of finished lanes ----
         ran = [i for i in range(N)
                if run[i] and self._flights[i] is not None]
-        events: List[BlockEvent] = []
         done_lanes = [i for i in ran if not live[i]]
-        toks = steps_arr = glens = None
-        if done_lanes:
+        with span("emit", ids=tracing.ids(self._flights[i].req.id
+                                          for i in done_lanes)):
+            events = self._emit(state, ran, live, steps_arr, t_done,
+                                bool(done_lanes))
+        if done_lanes and self.paged:
+            with span("evict"):
+                # return the finished lanes' pages to the pool *now* so the
+                # next admission sees them
+                drow = np.zeros((N,), bool)
+                drow[done_lanes] = True
+                state = self._jit_evict(state, jnp.asarray(drow))
+                self._host_evict(drow)
+        self._state = state
+        return events
+
+    def _count(self, admit: np.ndarray, calls: int,
+               steps: np.ndarray) -> None:
+        """Add one decode step's work to the counters, from the device's
+        forward count and per-lane iteration counts read at the sync
+        (admitted lanes restart theirs from 0)."""
+        c = self._counts
+        c["steps"] += 1
+        if admit.any():
+            c["admit_calls"] += 1
+            c["admitted_lanes"] += int(admit.sum())
+            self._steps_seen[admit] = 0
+        # the device's count is int32 and wraps
+        c["forwards"] += (calls - self._calls_seen) % (1 << 32)
+        self._calls_seen = calls
+        steps = steps.astype(np.int64)
+        c["lane_iters_active"] += int((steps - self._steps_seen).sum())
+        self._steps_seen = steps
+
+    def _emit(self, state: _SlotState, ran: List[int], live: np.ndarray,
+              steps_arr: np.ndarray, t_done: float,
+              finished: bool) -> List[BlockEvent]:
+        """Block events of the lanes that ran; a finished lane's final
+        event carries its :class:`GenerationOutput` and frees its lane."""
+        P, B = self.spec.prompt_len, self.spec.block_size
+        toks = glens = None
+        if finished:
             # full-canvas transfer only when a request completed (the
             # legacy cadence); in-flight boundaries move one block per
             # ran lane below
             toks = np.asarray(state.tokens)
-            steps_arr = np.asarray(state.steps)
             glens = np.asarray(self._jit_gen_lengths(state.tokens,
                                                      state.eos))
+        events: List[BlockEvent] = []
         for lane in ran:
             fl = self._flights[lane]
             blk = fl.blocks_done
@@ -1046,28 +1129,48 @@ class ContinuousEngine(_RequestStepper):
                 self._emitted.pop(fl.req.id, None)
                 self._arrival.pop(fl.req.id, None)
             events.append(ev)
-        if done_lanes and self.paged:
-            # return the finished lanes' pages to the pool *now* so the
-            # next admission sees them
-            drow = np.zeros((N,), bool)
-            drow[done_lanes] = True
-            state = self._jit_evict(state, jnp.asarray(drow))
-            self._host_evict(drow)
-        self._state = state
+        self._counts["blocks_emitted"] += len(events)
         return events
+
+    def counters(self) -> Dict[str, Any]:
+        """Work and host time since construction, cumulative (a reset for a
+        fresh ``stream()`` does not clear them), read lock-free as
+        :meth:`page_pool_stats` is:
+
+        - ``steps``: decode steps; ``admit_calls``, ``admitted_lanes``:
+          admission prefills and the lanes they admitted;
+        - ``forwards``: model forwards the device ran (its own count, read
+          at each step's sync), of which ``refine_forwards`` are the
+          refinement loop's: one per admission and one commit per step are
+          the rest;
+        - ``lane_iters_active``: refinement iterations summed over lanes
+          that still had a masked position, so ``lane_iters_active /
+          (refine_forwards * max_batch)`` is the share of lane-iterations
+          that did work;
+        - ``blocks_emitted``: block events returned;
+        - ``host_s``: host seconds per phase of
+          :data:`repro.serving.tracing.SPANS` (nested phases are also
+          inside their parent's time; ``driver.*`` is filled by an
+          ``EngineDriver`` over this engine).
+        """
+        c: Dict[str, Any] = dict(self._counts)
+        c["refine_forwards"] = (c["forwards"] - c["admit_calls"]
+                                - c["steps"])
+        c["host_s"] = dict(self.clock.host_s)
+        return c
 
     def page_pool_stats(self) -> Dict[str, float]:
         """Occupancy report since the last reset (paged layout; zeros for
         dense). Pages are sampled at every block boundary."""
-        if not self.paged or not self._pool_samples:
+        peak, total, n = self._pool
+        if not self.paged or not n:
             return {"n_pages": float(self.n_pages), "peak_pages": 0.0,
                     "avg_pages": 0.0, "peak_occupancy": 0.0,
                     "preemptions": 0.0, "stall_rounds": 0.0}
-        peak = max(self._pool_samples)
         return {
             "n_pages": float(self.n_pages),
             "peak_pages": float(peak),
-            "avg_pages": float(np.mean(self._pool_samples)),
+            "avg_pages": total / n,
             "peak_occupancy": peak / self.n_pages,
             "preemptions": float(self._preemptions),
             "stall_rounds": float(self._stall_rounds),
@@ -1076,10 +1179,17 @@ class ContinuousEngine(_RequestStepper):
     def concurrency_stats(self) -> Dict[str, float]:
         """Decoding-lane concurrency since the last reset, sampled at every
         block-level decode step (both layouts)."""
-        if not self._live_samples:
+        peak, total, n = self._lanes
+        if not n:
             return {"peak_lanes": 0.0, "avg_lanes": 0.0}
-        return {"peak_lanes": float(max(self._live_samples)),
-                "avg_lanes": float(np.mean(self._live_samples))}
+        return {"peak_lanes": float(peak), "avg_lanes": total / n}
+
+
+def _sample(acc: list, value: int) -> None:
+    """Fold one sample into a running ``[peak, sum, count]``."""
+    acc[0] = max(acc[0], value)
+    acc[1] += value
+    acc[2] += 1
 
 
 def make_engine(params, cfg: ModelConfig, serve: ServeConfig,

@@ -24,7 +24,17 @@ repo has no tokenizer):
 - ``GET /healthz`` — liveness (``{"status": "ok"}``).
 
 - ``GET /metrics`` — Prometheus text exposition surfacing the engine's
-  ``page_pool_stats()`` / ``concurrency_stats()`` plus request counters.
+  ``page_pool_stats()`` / ``concurrency_stats()`` as gauges, request
+  counters, the engine's work counters (``counters()``: decode steps,
+  admissions, forwards, active lane-iterations, blocks) as
+  ``cdlm_engine_<name>_total``, and the host seconds spent in each phase
+  of the serving loop as ``cdlm_engine_host_seconds_total{phase="..."}``
+  (phases in ``repro.serving.tracing``). The phase whose seconds grow
+  fastest holds the host: ``step`` is the whole engine step, ``sync`` its
+  wait for the device, ``admit``/``pages``/``decode``/``emit``/``evict``
+  its host work, ``sleep`` its idling to a future arrival, and
+  ``driver.wait``/``driver.lock``/``driver.route`` the scheduler thread
+  idle, waiting for a submitting handler, or handing events out.
 
 A single scheduler thread owns the engine (the engines are not
 thread-safe): HTTP handlers enqueue requests through
@@ -48,6 +58,7 @@ from typing import Dict
 
 import numpy as np
 
+from repro.serving import tracing
 from repro.serving.api import GenerationRequest, SamplingParams
 
 
@@ -70,6 +81,9 @@ class EngineDriver:
         self.requests_total = 0
         self.completed_total = 0
         self.aborted_total = 0
+        # the engine's phase clock, so driver and engine phases add up in
+        # one place (engines without one get the driver's own)
+        self._clock = getattr(engine, "clock", None) or tracing.PhaseClock()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="engine-driver")
         self._thread.start()
@@ -131,6 +145,15 @@ class EngineDriver:
             for k, v in src().items():
                 lines.append(f"# TYPE {prefix}_{k} gauge")
                 lines.append(f"{prefix}_{k} {v}")
+        counters = getattr(eng, "counters", None)
+        for k, v in (counters() if counters is not None else {}).items():
+            if k != "host_s":
+                lines.append(f"# TYPE cdlm_engine_{k}_total counter")
+                lines.append(f"cdlm_engine_{k}_total {v}")
+        lines.append("# TYPE cdlm_engine_host_seconds_total counter")
+        for phase, v in dict(self._clock.host_s).items():
+            lines.append(
+                f'cdlm_engine_host_seconds_total{{phase="{phase}"}} {v}')
         return "\n".join(lines) + "\n"
 
     def shutdown(self):
@@ -140,10 +163,16 @@ class EngineDriver:
         self._thread.join(timeout=5)
 
     def _loop(self):
+        span = self._clock.span
         while True:
-            with self.cond:
-                while not self._stop and not self.engine.has_unfinished():
-                    self.cond.wait(timeout=0.5)
+            with span("driver.lock"):
+                self.cond.acquire()
+            try:
+                if not self._stop and not self.engine.has_unfinished():
+                    with span("driver.wait"):
+                        while (not self._stop
+                               and not self.engine.has_unfinished()):
+                            self.cond.wait(timeout=0.5)
                 if self._stop:
                     return
                 try:
@@ -158,19 +187,23 @@ class EngineDriver:
                     for q in dead:
                         q.put(None)
                     return
-                routes = []
-                for ev in events:
-                    q = self._queues.get(ev.request_id)
-                    if q is None:
-                        continue  # aborted between steps
-                    routes.append((q, ev))
+                with span("driver.route"):
+                    routes = []
+                    for ev in events:
+                        q = self._queues.get(ev.request_id)
+                        if q is None:
+                            continue  # aborted between steps
+                        routes.append((q, ev))
+                        if ev.finished:
+                            self._queues.pop(ev.request_id, None)
+                            self.completed_total += 1
+            finally:
+                self.cond.release()
+            with span("driver.route"):
+                for q, ev in routes:
+                    q.put(ev)
                     if ev.finished:
-                        self._queues.pop(ev.request_id, None)
-                        self.completed_total += 1
-            for q, ev in routes:
-                q.put(ev)
-                if ev.finished:
-                    q.put(None)
+                        q.put(None)
 
 
 def _params_from_body(body: dict) -> SamplingParams:
