@@ -7,8 +7,8 @@ cache lengths, since paged decode serves lanes at mixed block offsets).
 
 Tuning: both ops take ``config=KernelConfig`` (see
 :mod:`repro.kernels.tuning`). For the dense kernel ``block_k`` is the cache
-tile; the paged kernel's page tile and lane grid are fixed by the pool's
-``page_size`` and page-table shape (chosen by the serving engine), so only
+tile; the paged kernel's chunk of pages follows from the pool's and the
+page table's shapes (``decode_attn.pages_per_step``), so only
 ``interpret`` resolves from the table there. The legacy ``block_k``/
 ``interpret`` kwargs stay as deprecated pass-throughs."""
 from __future__ import annotations
@@ -120,13 +120,44 @@ def paged_decode_attention(q, k_pages, v_pages, k_blk, v_blk, page_table,
     page_table: (b, n_tables) int32 (-1 = unallocated); cache_lens: scalar
     or (b,) int32 — per-lane valid cache prefix. Returns (b, Bq, Kv, G, hd)
     in q's dtype.
+
+    Under ``jax.vmap`` with lane-shared pools (the serving engine's lane
+    vmap) the mapped lanes fold into ``b``: one kernel call for all lanes,
+    not one per lane.
     """
-    b, Bq, Kv, G, hd = q.shape
     cfg = tuning.resolve(
         "decode_attn",
         config=tuning.merge_legacy(config, interpret=interpret),
         S=page_table.shape[1] * k_pages.shape[1])
-    interpret = cfg.interpret
+    attend = jax.custom_batching.custom_vmap(functools.partial(
+        _paged_attention, scale=scale, softcap=softcap, window=window,
+        interpret=cfg.interpret))
+
+    @attend.def_vmap
+    def _fold_lanes(n, in_batched, *args):
+        if in_batched[1] or in_batched[2]:
+            # per-lane pools: the kernel's own batching, one call per lane
+            axes = [0 if bt else None for bt in in_batched]
+            return jax.vmap(attend.fun, in_axes=axes)(*args), True
+        k_pages, v_pages = args[1:3]
+        q, k_blk, v_blk, page_table, cache_lens = [
+            a if bt else jnp.broadcast_to(a, (n, *a.shape))
+            for a, bt in zip(args[:1] + args[3:],
+                             in_batched[:1] + in_batched[3:])]
+        b = q.shape[1]
+        fold = lambda a: a.reshape(n * b, *a.shape[2:])  # noqa: E731
+        lens = jnp.broadcast_to(cache_lens.reshape(n, -1), (n, b))
+        out = attend(fold(q), k_pages, v_pages, fold(k_blk), fold(v_blk),
+                     fold(page_table), lens.reshape(n * b))
+        return out.reshape(n, b, *out.shape[1:]), True
+
+    return attend(q, k_pages, v_pages, k_blk, v_blk, page_table,
+                  jnp.asarray(cache_lens, jnp.int32))
+
+
+def _paged_attention(q, k_pages, v_pages, k_blk, v_blk, page_table,
+                     cache_lens, *, scale, softcap, window, interpret):
+    b, Bq, Kv, G, hd = q.shape
     qf = q.transpose(0, 2, 1, 3, 4).reshape(b, Kv, Bq * G, hd)
     kp = k_pages.transpose(2, 0, 1, 3)        # (Kv, n_pages, page, hd)
     vp = v_pages.transpose(2, 0, 1, 3)

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as st
+from _jaxpr_walk import paged_kernel_calls
 
 from repro.kernels.block_attn import block_attention_ref, flash_block_attention
 from repro.kernels.decode_attn import (
@@ -16,6 +17,7 @@ from repro.kernels.decode_attn import (
     paged_decode_attention,
     paged_decode_attention_ref,
 )
+from repro.kernels.decode_attn.decode_attn import pages_per_step
 from repro.kernels.xent import fused_xent, xent_ref
 
 
@@ -106,30 +108,46 @@ def _paged_inputs(key, b, Bq, Kv, G, hd, n_pages, page, n_t):
     return q, kp, vp, kb, vb
 
 
+# 16 lanes at mixed offsets: empty, one page, one past a page boundary, a full
+# table, and tables cut in chunks of pages_per_step (qwen2-like heads: 24
+# pages in 2-3 whole chunks; dream-like heads: 22 pages, a short last chunk)
+_LANES16 = (0, 32, 33, 768, 100, 31, 640, 0, 64, 65, 500, 767, 1, 320, 256,
+            700)
+_QWEN, _DREAM = (2, 7, 64), (4, 7, 128)
+
+
+def _paged_table(lens, page, n_t, n_pages):
+    """Scattered, non-monotone page assignment; unallocated tail slots -1."""
+    perm = np.random.default_rng(0).permutation(n_pages)
+    table = np.full((len(lens), n_t), -1, np.int32)
+    for lane, ln in enumerate(lens):
+        for j in range(-(-ln // page)):
+            table[lane, j] = perm[lane * n_t + j]
+    return jnp.asarray(table)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("page,n_t,lens,win,cap", [
-    (16, 5, (40, 32), None, None),
-    (16, 5, (0, 16), None, None),        # one empty lane, one 1-page lane
-    (32, 4, (100, 37), 48, None),        # boundary page + sliding window
-    (16, 3, (48, 48), None, 30.0),       # full tables + softcap
+@pytest.mark.parametrize("page,n_t,lens,win,cap,heads", [
+    (16, 5, (40, 32), None, None, (2, 4, 64)),
+    (16, 5, (0, 16), None, None, (2, 4, 64)),  # an empty lane, a 1-page lane
+    (32, 4, (100, 37), 48, None, (2, 4, 64)),  # boundary page + window
+    (16, 3, (48, 48), None, 30.0, (2, 4, 64)),  # full tables + softcap
+    (32, 24, _LANES16, None, None, _QWEN),
+    (32, 24, _LANES16, 200, None, _QWEN),
+    (32, 22, tuple(min(n, 704) for n in _LANES16), None, 30.0, _DREAM),
 ])
-def test_paged_decode_attn_vs_oracle(page, n_t, lens, win, cap, dtype):
+def test_paged_decode_attn_vs_oracle(page, n_t, lens, win, cap, heads,
+                                     dtype):
     """Paged kernel walks scattered, partially-allocated page tables with
     per-lane cache lengths and matches the gather-based oracle."""
-    b, Bq, Kv, G, hd = 2, 8, 2, 4, 64
-    n_pages = 12
+    (Kv, G, hd), b, Bq = heads, len(lens), 8
+    n_pages = max(12, b * n_t)
     rng = jax.random.PRNGKey(page + n_t)
     q, kp, vp, kb, vb = _paged_inputs(rng, b, Bq, Kv, G, hd, n_pages, page,
                                       n_t)
     q, kp, vp = q.astype(dtype), kp.astype(dtype), vp.astype(dtype)
     kb, vb = kb.astype(dtype), vb.astype(dtype)
-    # scattered, non-monotone page assignment; unallocated tail slots = -1
-    perm = np.random.default_rng(0).permutation(n_pages)
-    table = np.full((b, n_t), -1, np.int32)
-    for lane, ln in enumerate(lens):
-        for j in range(-(-ln // page)):
-            table[lane, j] = perm[lane * n_t + j]
-    table = jnp.asarray(table)
+    table = _paged_table(lens, page, n_t, n_pages)
     clens = jnp.asarray(lens, jnp.int32)
     out = paged_decode_attention(q, kp, vp, kb, vb, table, clens,
                                  scale=0.125, window=win, softcap=cap)
@@ -142,9 +160,54 @@ def test_paged_decode_attn_vs_oracle(page, n_t, lens, win, cap, dtype):
     assert float(jnp.max(jnp.abs(out - ref))) < tol
 
 
+def test_paged_decode_attn_lane_vmap_is_one_batched_call():
+    """``jax.vmap`` over one-lane calls (the serving engine's lane vmap,
+    shared pools) runs one kernel call over all lanes, with no loop around
+    it, and equals the batched call."""
+    b, Bq, (Kv, G, hd), page, n_t = 16, 8, _QWEN, 32, 24
+    n_pages = b * n_t
+    q, kp, vp, kb, vb = _paged_inputs(jax.random.PRNGKey(3), b, Bq, Kv, G,
+                                      hd, n_pages, page, n_t)
+    table = _paged_table(_LANES16, page, n_t, n_pages)
+    clens = jnp.asarray(_LANES16, jnp.int32)
+
+    def one(q1, kb1, vb1, t1, n1):
+        return paged_decode_attention(q1[None], kp, vp, kb1[None], vb1[None],
+                                      t1[None], n1, scale=0.125)[0]
+
+    lanes = jax.vmap(one)
+    calls = paged_kernel_calls(jax.make_jaxpr(lanes)(q, kb, vb, table,
+                                                     clens))
+    assert [shape for shape, _ in calls] == [(b, n_t)]
+    assert not any("while" in outer for _, outer in calls)
+    batched = paged_decode_attention(q, kp, vp, kb, vb, table, clens,
+                                     scale=0.125)
+    assert np.array_equal(np.asarray(lanes(q, kb, vb, table, clens)),
+                          np.asarray(batched))
+
+
+def test_paged_decode_attn_vmap_over_pools_matches_per_lane():
+    """Pools batched with the lanes (one pool per mapped element) keep the
+    kernel's own batching and match calls made one by one."""
+    n, b, Bq, Kv, G, hd, page, n_t = 3, 2, 8, 2, 4, 64, 16, 4
+    ks = jax.random.split(jax.random.PRNGKey(5), n)
+    ins = [_paged_inputs(k, b, Bq, Kv, G, hd, b * n_t, page, n_t)
+           for k in ks]
+    q, kp, vp, kb, vb = (jnp.stack(a) for a in zip(*ins))
+    table = jnp.arange(b * n_t, dtype=jnp.int32).reshape(b, n_t)
+    clens = jnp.asarray([[50, 17], [0, 64], [33, 1]], jnp.int32)
+    fn = lambda *a: paged_decode_attention(*a, scale=0.125)  # noqa: E731
+    out = jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, None, 0))(
+        q, kp, vp, kb, vb, table, clens)
+    for i in range(n):
+        ref = fn(q[i], kp[i], vp[i], kb[i], vb[i], table, clens[i])
+        assert np.allclose(np.asarray(out[i]), np.asarray(ref), atol=1e-5)
+
+
 def test_paged_decode_attn_matches_dense_on_contiguous_layout():
     """With an identity page table the paged kernel must reproduce the dense
-    flash-decode kernel exactly (same tiles, same online-softmax order)."""
+    flash-decode kernel exactly when the dense cache tile is the paged
+    kernel's chunk of pages (same tiles, same online-softmax order)."""
     b, Bq, Kv, G, hd = 2, 8, 2, 4, 64
     page, n_t = 16, 5
     q, kp, vp, kb, vb = _paged_inputs(jax.random.PRNGKey(7), b, Bq, Kv, G,
@@ -153,8 +216,9 @@ def test_paged_decode_attn_matches_dense_on_contiguous_layout():
     kc = kp.reshape(b, n_t * page, Kv, hd)
     vc = vp.reshape(b, n_t * page, Kv, hd)
     clen = 40
+    chunk = page * pages_per_step(page, Kv, hd, kp.dtype.itemsize, n_t)
     dense = decode_attention(q, kc, vc, kb, vb, jnp.asarray(clen),
-                             scale=0.125, block_k=page)
+                             scale=0.125, block_k=chunk)
     paged = paged_decode_attention(q, kp, vp, kb, vb, table,
                                    jnp.full((b,), clen, jnp.int32),
                                    scale=0.125)

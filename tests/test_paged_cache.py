@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _jaxpr_walk import paged_kernel_calls
 
 from repro.configs.base import ServeConfig
 from repro.configs.registry import get_config
@@ -189,6 +190,24 @@ def test_engine_paged_kernel_path(params, requests, dense_responses):
     eng.warmup()
     _assert_same_responses(dense_responses,
                            {r.id: r for r in eng.generate(requests)})
+
+
+def test_engine_paged_kernel_one_call_per_layer_scan(params):
+    """The engine's decode step runs the paged kernel once per layer-scan
+    body for all lanes (refinement forward and commit forward): the lane
+    vmap reaches the kernel as a (lanes, n_tables) page table, with no
+    per-lane loop between the layer scan and the kernel."""
+    eng = ContinuousEngine(params, CFG, _serve(cache_layout="paged"),
+                           prompt_len=P, use_paged_kernel=True)
+    run = jnp.ones((eng.n_lanes,), bool)
+    calls = paged_kernel_calls(jax.make_jaxpr(
+        lambda p, s, r: eng._decode_block(p, s, r, sampled=False))(
+            params, eng._state, run))
+    n_t = eng._state.cache.page_table.shape[1]
+    assert [shape for shape, _ in calls] == [(eng.n_lanes, n_t)] * 2
+    for _, outer in calls:
+        assert "scan" in outer
+        assert "while" not in outer[outer.index("scan"):]
 
 
 def test_paged_kernel_requires_paged_layout(params):

@@ -1,6 +1,7 @@
 """Compile-only checks of the main-path Pallas kernels for a TPU v5e that is
 described, not attached: the chip's own compiler (Mosaic) must accept each
-kernel at qwen2-0.5b widths in bfloat16, and the compiled program must
+kernel at qwen2-0.5b widths in bfloat16 (the paged kernel at dream-7b
+widths too), and the compiled program must
 contain the kernel as a ``tpu_custom_call``. Interpret-mode tests cannot
 see layout or tiling refusals; this file can, without a chip.
 
@@ -97,14 +98,18 @@ def test_decode_attention_compiles(shape):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_attention_compiles(shape):
-    kv, g, hd = CFG.n_kv_heads, CFG.q_per_kv, CFG.head_dim
-    n_t = (PROMPT + GEN) // BLOCK
-    n_pages = LANES * n_t
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "dream-7b"])
+def test_paged_decode_attention_compiles(shape, arch):
+    """The lane-batched paged kernel as the serving engine calls it: 16
+    lanes, 24-page tables of 32 slots, a 384-page pool. Its double-buffered
+    chunk of pages must fit the kernel's VMEM at both head shapes."""
+    cfg = get_config(arch)
+    kv, g, hd = cfg.n_kv_heads, cfg.q_per_kv, cfg.head_dim
+    lanes, n_t, n_pages = 16, (PROMPT + GEN) // BLOCK, 384
     text = _compile_text(
         lambda q, k, v, pt, n: paged_decode_attention_partial(
             q, k, v, pt, n, scale=hd ** -0.5, g=g, interpret=False),
-        shape((LANES, kv, BLOCK * g, hd), BF16),
+        shape((lanes, kv, BLOCK * g, hd), BF16),
         shape((kv, n_pages, BLOCK, hd), BF16), shape((kv, n_pages, BLOCK, hd), BF16),
-        shape((LANES, n_t), jnp.int32), shape((LANES,), jnp.int32))
+        shape((lanes, n_t), jnp.int32), shape((lanes,), jnp.int32))
     assert "tpu_custom_call" in text
