@@ -13,18 +13,9 @@ from __future__ import annotations
 
 from typing import Dict, Iterable
 
+from bench import families
+
 BF16 = 2
-
-
-def _dims(m: dict):
-    return (m["d_model"], m["head_dim"], m["n_heads"], m["n_kv_heads"],
-            m["d_ff"], m["vocab_size"], m["n_layers"])
-
-
-def layer_params(m: dict) -> int:
-    """Matmul weights of one decoder layer (projections + gated FFN)."""
-    d, hd, nq, nkv, ff, _, _ = _dims(m)
-    return d * nq * hd + 2 * d * nkv * hd + nq * hd * d + 3 * d * ff
 
 
 def select_call(m: dict, rows: int) -> Dict[str, float]:
@@ -42,7 +33,7 @@ def paged_attn_call(m: dict, block: int,
     each lane's ``block`` queries attend to its ``ctx`` cached positions
     and its own block. Keys and values are read once, queries read and
     outputs written once."""
-    _, hd, nq, nkv, _, _, _ = _dims(m)
+    hd, nq, nkv = m["head_dim"], m["n_heads"], m["n_kv_heads"]
     flops = bytes_ = 0.0
     for ctx in contexts:
         keys = ctx + block
@@ -54,9 +45,11 @@ def paged_attn_call(m: dict, block: int,
 def forward_flops(m: dict, tokens: int, ctx: int, unembed: bool) -> float:
     """FLOPs of ``tokens`` new positions of one sequence that attend to
     ``ctx`` earlier positions and to each other (all layers), with or
-    without the unembedding."""
-    d, hd, nq, _, _, V, L = _dims(m)
-    f = tokens * L * 2 * layer_params(m)
+    without the unembedding. The layers' matmul FLOPs are the model
+    family's (``layer_flops``)."""
+    d, hd, nq, V, L = (m["d_model"], m["head_dim"], m["n_heads"],
+                       m["vocab_size"], m["n_layers"])
+    f = L * families.of(m).layer_flops(m, tokens)
     f += L * 4 * tokens * (ctx + tokens) * nq * hd
     if unembed:
         f += tokens * 2 * d * V

@@ -4,7 +4,9 @@ configuration file, drive it with a traffic mix through ``EngineDriver``
 tokens against the plain reference, and reduce everything to metrics.
 
 Everything a configuration, a mix or a per-layer metric owns lives in its
-own file (``configs/``, ``traffic/``, ``metrics/``), found by name.
+own file (``configs/``, ``traffic/``, ``metrics/``), found by name; what
+depends on the decoder layer lives in the configuration's model family
+(``families/``).
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import spec, traffic
+from bench import families, spec, traffic
 from bench import trace as tr
 from bench.weights import make_weights, to_program_layout
 
@@ -114,8 +116,20 @@ class Cell:
                    b, limits)
 
     @property
+    def family(self):
+        """The configuration's model family (``bench/families/``)."""
+        return families.of(self.config)
+
+    @property
+    def model_keys(self) -> tuple:
+        return spec.MODEL_KEYS + self.family.KEYS
+
+    @property
     def model(self) -> dict:
-        return {k: self.config[k] for k in spec.MODEL_KEYS}
+        m = {k: self.config[k] for k in self.model_keys}
+        if "family" in self.config:
+            m["family"] = self.config["family"]
+        return m
 
     @property
     def serve(self) -> dict:
@@ -129,7 +143,8 @@ def program_config(cell: Cell):
     from repro.configs.registry import get_config
     cfg = dc.replace(get_config(cell.config["arch"]),
                      **cell.config["overrides"])
-    for k, v in cell.model.items():
+    for k in cell.model_keys:
+        v = cell.config[k]
         if getattr(cfg, k) != v:
             raise ValueError(f"program config {cfg.name}: {k}="
                              f"{getattr(cfg, k)!r}, configuration file says "
@@ -486,7 +501,7 @@ def serve(cell: Cell, seed: int, t_start: float, *, require_tpu: bool = True,
 
     cfg = program_config(cell)
     weights = make_weights(cell.model, seed)
-    params = to_program_layout(weights)
+    params = to_program_layout(weights, cell.model)
     engine = build_engine(cell, params, cfg)
     engine.warmup()
     if require_tpu:
@@ -554,7 +569,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         if keep_trace:
             tr.save(compact, keep_trace)
         shutil.rmtree(log_dir, ignore_errors=True)
-        trace_info = tr.reduce(compact)
+        trace_info = tr.reduce(compact,
+                               kernels=getattr(cell.family, "KERNELS", None))
 
     reqs = load.reqs
     steps = [s for s in recorder.steps if ws <= s.t0 < we]

@@ -1,12 +1,12 @@
 """Plain reference of the served model, and the check that decides
 ``correct``.
 
-A dense GQA transformer with QKV bias, RoPE, RMSNorm and a SwiGLU MLP
-(Qwen2 report, arXiv:2407.10671; Dream-7B shares the backbone), written
-here from the published equations in ``jax.numpy`` and float32 at
-``highest`` matmul precision. It imports nothing of the serving program:
-it reads the benchmark's own weights (``bench/weights.py``) and the model
-numbers of the configuration file.
+The embedding, the model family's decoder layers (``bench/families/``,
+one at a time), the final RMSNorm and the head, written from the
+published equations in ``jax.numpy`` and float32 at ``highest`` matmul
+precision. It imports nothing of the serving program: it reads the
+benchmark's own weights (``bench/weights.py``) and the model numbers of
+the configuration file.
 
 Under CDLM's block-causal mask the prompt attends to itself, and a block
 attends to the prompt, every earlier block and the whole of itself; so a
@@ -29,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import families
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0  # largest finite float8_e4m3fn
@@ -62,52 +64,17 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(m, low, x, lw, past_k, past_v, past_ok, pos):
-    """One decoder layer. x: (S, L, d) fp32; past_k/v: (S, T, nkv, hd);
-    past_ok: (T,) which past positions exist. Returns (x, k, v)."""
-    S, L, _ = x.shape
-    nq, nkv, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    h = _rmsnorm(x, lw["norm1"], m["norm_eps"])
-    q = _mm(h, lw["wq"], low)
-    k = _mm(h, lw["wk"], low)
-    v = _mm(h, lw["wv"], low)
-    if "bq" in lw:
-        q = q + lw["bq"].astype(jnp.float32)
-        k = k + lw["bk"].astype(jnp.float32)
-        v = v + lw["bv"].astype(jnp.float32)
-    q = _rope(q.reshape(S, L, nq, hd), pos, m["rope_theta"])
-    k = _rope(k.reshape(S, L, nkv, hd), pos, m["rope_theta"])
-    v = v.reshape(S, L, nkv, hd)
-    kk = jnp.concatenate([past_k, k], 1)
-    vv = jnp.concatenate([past_v, v], 1)
-    ok = jnp.concatenate([past_ok, jnp.ones((L,), bool)])
-    g = nq // nkv
-    qg = q.reshape(S, L, nkv, g, hd)
-    s = jnp.einsum("slkgh,stkh->skglt", qg, kk, precision=HIGHEST)
-    s = jnp.where(ok, s * hd ** -0.5, -jnp.inf)
-    p = jax.nn.softmax(s, -1)
-    o = jnp.einsum("skglt,stkh->slkgh", p, vv, precision=HIGHEST)
-    x = x + _mm(o.reshape(S, L, nq * hd), lw["wo"], low)
-    h = _rmsnorm(x, lw["norm2"], m["norm_eps"])
-    a = _mm(h, lw["wi_gate"], low)
-    x = x + _mm(jax.nn.silu(a) * _mm(h, lw["wi_up"], low), lw["wo_mlp"], low)
-    return x, k, v
-
-
-LAYER_KEYS = ("norm1", "norm2", "wq", "wk", "wv", "wo", "bq", "bk", "bv",
-              "wi_gate", "wi_up", "wo_mlp")
-
-
 def _stack(m, low, w, x, kbuf, vbuf, past_len, pos):
     """All layers, one at a time (``lax.scan``), each upcast on its own.
     kbuf/vbuf: (n_layers, S, T, nkv, hd) past keys/values, valid below
     ``past_len``. Returns (x, new k, new v) with k/v stacked by layer."""
-    layers = {k: w[k] for k in LAYER_KEYS if k in w}
+    family = families.of(m)
+    layers = {k: w[k] for k in family.shapes(m)}
     past_ok = jnp.arange(kbuf.shape[2]) < past_len
 
     def body(x, xs):
         lw, pk, pv = xs
-        x, k, v = _layer(m, low, x, lw, pk, pv, past_ok, pos)
+        x, k, v = family.layer(m, low, x, lw, pk, pv, past_ok, pos)
         return x, (k, v)
 
     x, (k, v) = jax.lax.scan(body, x, (layers, kbuf, vbuf))

@@ -12,9 +12,11 @@ import re
 
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 UNIT = re.compile(r"[A-Za-z0-9_/%.-]{1,16}")
+# the model keys every configuration file carries; a model family
+# (``bench/families/``) reads its own ``KEYS`` besides
 MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab_size", "qkv_bias", "tie_embeddings",
-              "rope_theta", "norm_eps", "mask_token_id", "eos_token_id")
+              "vocab_size", "qkv_bias", "tie_embeddings", "rope_theta",
+              "norm_eps", "mask_token_id", "eos_token_id")
 
 
 def _name(v, what):

@@ -117,12 +117,12 @@ KERNELS = {
 }
 
 
-def kernel_kind(meta: dict):
-    """Which of :data:`KERNELS` an op is (None if it is no TPU custom
-    call or none of them), from the op's HLO text."""
+def kernel_kind(meta: dict, kernels: dict = KERNELS):
+    """Which of ``kernels`` an op is (None if it is no TPU custom call or
+    none of them), from the op's HLO text."""
     if not meta.get("kernel"):
         return None
-    for kind, pat in KERNELS.items():
+    for kind, pat in kernels.items():
         if pat.search(meta["text"]):
             return kind
     return None
@@ -137,9 +137,12 @@ def _span_at(spans, t):
     return best[0] if best else None
 
 
-def reduce(compact: dict, top: int = 10) -> dict:
+def reduce(compact: dict, top: int = 10,
+           kernels: dict | None = None) -> dict:
     """Numbers of the window spanned by the ``bench.window`` host span
-    (the whole trace if there is none), averaged over devices:
+    (the whole trace if there is none), averaged over devices. ``kernels``
+    (a model family's ``KERNELS``) adds kinds of kernel to
+    :data:`KERNELS`:
 
     ``window_s``, ``busy_s`` (union of op intervals), ``programs``
     {program: device seconds, from the modules line}, ``ops`` {op: device
@@ -148,6 +151,10 @@ def reduce(compact: dict, top: int = 10) -> dict:
     ``top`` ops by time) and ``idle_gaps`` (the ``top`` longest gaps,
     named by the programs around them and the harness span the host was
     in)."""
+    clash = set(KERNELS) & set(kernels or {})
+    if clash:
+        raise ValueError(f"kernel kinds {sorted(clash)} are the trace's own")
+    kinds = dict(KERNELS, **(kernels or {}))
     spans = [tuple(s) for s in compact["spans"]]
     win = [s for s in spans if s[0] == "bench.window"]
     devices = compact["devices"]
@@ -198,9 +205,9 @@ def reduce(compact: dict, top: int = 10) -> dict:
         "ops": {k: v * to_s for k, v in ops.items()},
         "spans": [list(x) for x in spans],
         "op_events": [[nm, s, e] for nm, s, e in _clip(dev0["ops"], lo, hi)],
-        "kernels": {k: kernel_kind(v)
+        "kernels": {k: kernel_kind(v, kinds)
                     for k, v in compact.get("op_meta", {}).items()
-                    if kernel_kind(v)},
+                    if kernel_kind(v, kinds)},
         "device_ops": sorted(([k, v * to_s] for k, v in ops.items()),
                              key=lambda x: -x[1])[:top],
         "idle_gaps": gaps,

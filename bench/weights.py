@@ -5,14 +5,20 @@ consumes (``params["embed"|"final_norm"|"slots"]``, layers stacked on axis
 0 of each leaf) and in the dtype they are served in. The plain reference
 reads the same arrays: they come from the benchmark, not the program.
 
-Scales: projections N(0, 1/fan_in); token embedding N(0, 0.02^2);
-QKV biases N(0, 0.1^2) and norm gains 1 + N(0, 0.1^2), so the bias and
-gain paths carry non-trivial values.
+Leaves are the model family's per-layer ones (``bench/families/``) and
+the embedding, final norm and untied head. Scales by kind: ``mat``
+N(0, 1/fan_in); ``embed`` N(0, 0.02^2); ``bias`` N(0, 0.1^2) and ``gain``
+1 + N(0, 0.1^2), so the bias and gain paths carry non-trivial values. A
+family with kinds of its own draws them itself.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from bench import families
+
+KINDS = ("mat", "embed", "bias", "gain")  # drawn here; a family draws others
 
 
 def root_key(seed: int):
@@ -24,27 +30,19 @@ def root_key(seed: int):
 
 
 def shapes(m: dict) -> dict:
-    """Leaf name -> (shape, kind) for the model dict ``m``."""
-    L, d, hd = m["n_layers"], m["d_model"], m["head_dim"]
-    nq, nkv, ff, V = m["n_heads"], m["n_kv_heads"], m["d_ff"], m["vocab_size"]
-    out = {
-        "tok": ((V, d), "embed"),
-        "final_norm": ((d,), "gain"),
-        "norm1": ((L, d), "gain"), "norm2": ((L, d), "gain"),
-        "wq": ((L, d, nq * hd), "mat"), "wk": ((L, d, nkv * hd), "mat"),
-        "wv": ((L, d, nkv * hd), "mat"), "wo": ((L, nq * hd, d), "mat"),
-        "wi_gate": ((L, d, ff), "mat"), "wi_up": ((L, d, ff), "mat"),
-        "wo_mlp": ((L, ff, d), "mat"),
-    }
-    if m["qkv_bias"]:
-        out.update(bq=((L, nq * hd), "bias"), bk=((L, nkv * hd), "bias"),
-                   bv=((L, nkv * hd), "bias"))
+    """Leaf name -> (shape, kind) for the model dict ``m``: its family's
+    per-layer leaves, the embedding, the final norm and an untied head."""
+    d, V = m["d_model"], m["vocab_size"]
+    out = dict(families.of(m).shapes(m), tok=((V, d), "embed"),
+               final_norm=((d,), "gain"))
     if not m["tie_embeddings"]:
         out["head"] = ((d, V), "mat")
     return out
 
 
-def _leaf(key, shape, kind, dtype):
+def _leaf(family, key, shape, kind, dtype):
+    if kind not in KINDS:
+        return family.leaf(key, shape, kind, dtype)
     z = jax.random.normal(key, shape, jnp.float32)
     if kind == "mat":
         z = z * shape[-2] ** -0.5
@@ -60,24 +58,19 @@ def _leaf(key, shape, kind, dtype):
 def flat_weights(m: dict, key, dtype=jnp.bfloat16) -> dict:
     """Every leaf by name; leaf ``i`` (sorted names) draws from
     ``fold_in(key, i)``."""
-    spec = shapes(m)
-    return {name: _leaf(jax.random.fold_in(key, i), *spec[name], dtype)
+    spec, family = shapes(m), families.of(m)
+    return {name: _leaf(family, jax.random.fold_in(key, i), *spec[name],
+                        dtype)
             for i, name in enumerate(sorted(spec))}
 
 
-def to_program_layout(w: dict) -> dict:
+def to_program_layout(w: dict, m: dict) -> dict:
     """The flat leaves arranged as the serving program's params tree."""
-    attn = {k: w[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
-            if k in w}
-    slot = {"norm1": {"w": w["norm1"]}, "norm2": {"w": w["norm2"]},
-            "attn": attn,
-            "mlp": {"wi_gate": w["wi_gate"], "wi_up": w["wi_up"],
-                    "wo": w["wo_mlp"]}}
     embed = {"tok": w["tok"]}
     if "head" in w:
         embed["head"] = w["head"]
     return {"embed": embed, "final_norm": {"w": w["final_norm"]},
-            "slots": (slot,)}
+            "slots": (families.of(m).program_slot(w),)}
 
 
 def make_weights(m: dict, seed: int) -> dict:

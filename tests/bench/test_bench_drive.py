@@ -18,7 +18,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from bench import harness, spec  # noqa: E402
+from bench import families, harness, spec  # noqa: E402
 
 # the tiny cell's limit, set as the cells' are (PERF.md): sound runs read
 # 0 on seeds 1-10 at this size, the float8 control 0.08-0.16
@@ -31,7 +31,8 @@ def tiny_cell(tau=0.9, loop="closed"):
     over = {k: getattr(cfg, k) for k in (
         "n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
         "vocab_size", "mask_token_id", "eos_token_id", "dtype")}
-    config = dict({k: getattr(cfg, k) for k in spec.MODEL_KEYS},
+    keys = spec.MODEL_KEYS + families.load("dense").KEYS
+    config = dict({k: getattr(cfg, k) for k in keys},
                   arch="qwen2-0.5b", overrides=over, prompt_id_max=500,
                   serve={"max_batch": 4, "block_size": 8, "prompt_len": 32,
                          "gen_length": 32},

@@ -12,6 +12,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..", "..")
 sys.path.insert(0, ROOT)
 
 from bench import cost, spec, traffic  # noqa: E402
+from bench.families import dense  # noqa: E402
 from bench.peaks import peaks  # noqa: E402
 
 
@@ -25,7 +26,7 @@ QWEN, DREAM = _config("qwen2-0.5b"), _config("dream-7b-l14")
 
 def test_qwen_costs_by_hand():
     # attention 896*896*2 + 2*896*128, SwiGLU 3*896*4864
-    assert cost.layer_params(QWEN) == 802_816 * 2 + 229_376 + 13_074_432
+    assert dense.layer_params(QWEN) == 802_816 * 2 + 229_376 + 13_074_432
     sel = cost.select_call(QWEN, 512)
     assert sel["flops"] == 139_401_887_744          # 2*512*896*151936
     assert sel["bytes"] == 272_269_312 + 917_504 + 6_144
@@ -37,7 +38,7 @@ def test_qwen_costs_by_hand():
 
 
 def test_dream_costs_by_hand():
-    assert cost.layer_params(DREAM) == 233_046_016
+    assert dense.layer_params(DREAM) == 233_046_016
     sel = cost.select_call(DREAM, 512)
     assert sel["flops"] == 2 * 512 * 3584 * 152_064
     assert sel["bytes"] == 3584 * 152_064 * 2 + 512 * 3584 * 2 + 512 * 12
